@@ -165,4 +165,7 @@ def run(print_fn=print) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

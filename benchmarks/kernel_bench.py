@@ -1,9 +1,12 @@
-"""Kernel microbenchmarks: interpret-mode Pallas vs. jnp reference.
+"""Kernel microbenchmarks: Pallas kernels vs. jnp reference.
 
-On CPU the interpret path measures *correct execution* of the exact TPU
-program (not TPU speed); the derived column reports the achieved
-bandwidth of the jnp reference as the apples-to-apples CPU number and
-the analytic TPU-roofline time for the kernel's traffic.
+The kernels run in the mode ``interpret=None`` resolves to: compiled on
+a TPU, interpret mode elsewhere, where the timing measures *correct
+execution* of the exact TPU program (not TPU speed) and the row names
+say ``interpret``.  The derived column reports the analytic roofline
+time for each kernel's traffic, priced at the peaks of the chip the
+bench runs on (``repro.launch.roofline.peaks``) or, off the chip, of
+the modeled v5e.
 
 Emits CSV rows and a machine-readable ``BENCH_kernels.json`` (µs per
 call, modeled HBM bytes, TPU roofline µs per kernel).
@@ -19,8 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.launch.roofline import MODEL_KIND, peaks
 
-HBM_BW = 819e9
 BENCH_DIR = os.environ.get("BENCH_DIR", ".")
 
 
@@ -34,6 +37,10 @@ def _time(fn, *args, iters=3):
 
 
 def run(print_fn=print):
+    dev = jax.devices()[0]
+    chip = peaks(dev.device_kind if dev.platform == "tpu" else MODEL_KIND)
+    hbm_bw = chip["hbm_bw"]
+    mode = "interpret" if ops._default_interpret() else "compiled"
     rng = np.random.default_rng(0)
     report: dict = {}
 
@@ -58,7 +65,7 @@ def run(print_fn=print):
     w = jnp.asarray(rng.normal(size=(d,)), jnp.float32)
     us_ref = _time(jax.jit(ops.trigger_sq_norms_ref), z, w)
     bytes_moved = (n * d + d) * 4
-    tpu_us = bytes_moved / HBM_BW * 1e6
+    tpu_us = bytes_moved / hbm_bw * 1e6
     print_fn(f"trigger_norms_ref_jnp,{us_ref:.1f},"
              f"tpu_roofline_us={tpu_us:.1f}")
     record("trigger_norms_ref_jnp", us_ref, hbm_bytes=bytes_moved,
@@ -70,7 +77,7 @@ def run(print_fn=print):
     us_ref = _time(jax.jit(lambda a, b, c: ops.admm_update_ref(a, b, c)),
                    th, la, w)
     bytes_moved = n * d * 4 * 5  # 2 reads + 3 writes (ω cached)
-    tpu_us = bytes_moved / HBM_BW * 1e6
+    tpu_us = bytes_moved / hbm_bw * 1e6
     print_fn(f"admm_update_ref_jnp,{us_ref:.1f},"
              f"tpu_roofline_us={tpu_us:.1f}")
     record("admm_update_ref_jnp", us_ref, hbm_bytes=bytes_moved,
@@ -80,7 +87,7 @@ def run(print_fn=print):
     bytes_pre = n * d * 4 * 4
     report["admm_update_presolve_modeled"] = {
         "us_per_call": None, "modeled_hbm_bytes": bytes_pre,
-        "tpu_roofline_us": bytes_pre / HBM_BW * 1e6,
+        "tpu_roofline_us": bytes_pre / hbm_bw * 1e6,
         "note": "with_z=False round form (2 reads + 2 writes)",
     }
 
@@ -100,7 +107,7 @@ def run(print_fn=print):
     us_ref = _time(jax.jit(lambda *a: ops.fused_gss_ref(*a, with_z=True)),
                    gidx, gvalid, gsolved, gw, gth, gla, gz)
     bytes_moved = fused_gss_hbm_bytes(gc, gd, with_z=True)
-    tpu_us = bytes_moved / HBM_BW * 1e6
+    tpu_us = bytes_moved / hbm_bw * 1e6
     print_fn(f"fused_gss_ref_jnp,{us_ref:.1f},"
              f"tpu_roofline_us={tpu_us:.1f}")
     record("fused_gss_ref_jnp", us_ref, hbm_bytes=bytes_moved,
@@ -111,7 +118,7 @@ def run(print_fn=print):
     bytes_3pass = 4 * (10 * gc * gd + gd)
     report["fused_gss_unfused_3pass_modeled"] = {
         "us_per_call": None, "modeled_hbm_bytes": bytes_3pass,
-        "tpu_roofline_us": bytes_3pass / HBM_BW * 1e6,
+        "tpu_roofline_us": bytes_3pass / hbm_bw * 1e6,
         "note": "reference gather + z-assembly + 3-scatter commit "
                 "traffic over the same planned rows",
     }
@@ -124,7 +131,7 @@ def run(print_fn=print):
     us_ref = _time(jax.jit(
         lambda q, k, v: ops.flash_attention_ref(q, k, v)), q, k, v)
     flops = 2 * 2 * b * h * s * s * hd  # qk + pv
-    tpu_us = flops / 197e12 * 1e6
+    tpu_us = flops / chip["bf16_flops"] * 1e6
     print_fn(f"flash_attention_ref_jnp,{us_ref:.1f},"
              f"tpu_compute_roofline_us={tpu_us:.2f}")
     record("flash_attention_ref_jnp", us_ref, flops=flops,
@@ -137,36 +144,33 @@ def run(print_fn=print):
     us_ref = _time(jax.jit(lambda s_, d_: ops.ssd_scan_ref(s_, d_)[0]),
                    states, decays)
     bytes_moved = states.size * 4 * 2
-    tpu_us = bytes_moved / HBM_BW * 1e6
+    tpu_us = bytes_moved / hbm_bw * 1e6
     print_fn(f"ssd_scan_ref_jnp,{us_ref:.1f},"
              f"tpu_roofline_us={tpu_us:.1f}")
     record("ssd_scan_ref_jnp", us_ref, hbm_bytes=bytes_moved,
            tpu_roofline_us=tpu_us)
 
-    # interpret-mode kernels (correctness-path timing, CPU-only number)
-    us_k = _time(lambda: ops.trigger_sq_norms(z[:8, :4096], w[:4096],
-                                              interpret=True))
-    print_fn(f"trigger_norms_pallas_interpret_small,{us_k:.1f},"
-             f"interpret_mode=True")
-    record("trigger_norms_pallas_interpret_small", us_k,
-           note="interpret mode (CPU correctness path)")
+    # Pallas kernels at a small slice (off the chip: interpret mode, a
+    # correctness-path timing and a CPU-only number)
+    us_k = _time(lambda: ops.trigger_sq_norms(z[:8, :4096], w[:4096]))
+    print_fn(f"trigger_norms_pallas_{mode}_small,{us_k:.1f},mode={mode}")
+    record(f"trigger_norms_pallas_{mode}_small", us_k,
+           note=f"{mode} mode")
 
     us_k = _time(lambda: ops.admm_update(th[:8, :4096], la[:8, :4096],
-                                         w[:4096], interpret=True,
-                                         with_z=False)[0])
-    print_fn(f"admm_update_pallas_interpret_small,{us_k:.1f},"
-             f"interpret_mode=True with_z=False")
-    record("admm_update_pallas_interpret_small", us_k,
-           note="interpret mode, with_z=False (round form)")
+                                         w[:4096], with_z=False)[0])
+    print_fn(f"admm_update_pallas_{mode}_small,{us_k:.1f},"
+             f"mode={mode} with_z=False")
+    record(f"admm_update_pallas_{mode}_small", us_k,
+           note=f"{mode} mode, with_z=False (round form)")
 
     us_k = _time(lambda: ops.fused_gss(
         gidx[:8], gvalid[:8], gsolved[:8, :4096], gw[:4096],
-        gth[:, :4096], gla[:, :4096], gz[:, :4096], interpret=True)[0])
-    print_fn(f"fused_gss_pallas_interpret_small,{us_k:.1f},"
-             f"interpret_mode=True with_z=True")
-    record("fused_gss_pallas_interpret_small", us_k,
-           note="interpret mode, 8 slots of the (1024, 4096) state "
-                "(CPU correctness path)")
+        gth[:, :4096], gla[:, :4096], gz[:, :4096])[0])
+    print_fn(f"fused_gss_pallas_{mode}_small,{us_k:.1f},"
+             f"mode={mode} with_z=True")
+    record(f"fused_gss_pallas_{mode}_small", us_k,
+           note=f"{mode} mode, 8 slots of the (1024, 4096) state")
 
     import platform
     report["_env"] = (f"jax={jax.__version__};"
@@ -180,4 +184,7 @@ def run(print_fn=print):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

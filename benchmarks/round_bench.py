@@ -596,4 +596,7 @@ def run(print_fn=print, *, n_clients: int = 1024, n_points: int = 16,
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

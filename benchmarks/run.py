@@ -35,6 +35,10 @@ def main() -> None:
     only = set(args.only.split(","))
     datasets = args.datasets.split(",")
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import fig1, kernel_bench, round_bench, roofline_bench, table1, \
         table2
 

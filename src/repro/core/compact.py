@@ -401,17 +401,16 @@ def shard_mapped_block(block: Callable, mesh, *, axis: str = "clients",
     so a shard's solves read exactly its own clients' slices and
     gather/solve/scatter still never cross devices.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     c, r = P(axis), P()
     data_spec = (r, r) if ragged else (c, c)
     extra = (c, c) if ragged else ()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         block, mesh=mesh,
         in_specs=(c, c, c, c, c, c, c, c, r) + data_spec + (c,) + extra,
         out_specs=(c, c, c, c, c, c, c, c, c),
-        check_rep=False)
+        check_vma=False)
     info = getattr(block, "static_info", None)
     if info is not None:  # carried through for the analysis layer
         mapped.static_info = dict(info, n_shards=mesh.shape[axis])

@@ -57,6 +57,7 @@ from __future__ import annotations
 
 from functools import partial
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -227,16 +228,15 @@ def _ef_body(z, omega, resid, mask, denom, *, mode, block, axis,
 
 
 def _mapped(body, mesh, axis, *, with_mask):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     c, r = P(axis), P()
     in_specs = (c, r, c, c, r) if with_mask else (c, r, c)
-    # check_rep=False: psum/pmax/all_gather outputs are replicated by
+    # check_vma=False: psum/pmax/all_gather outputs are replicated by
     # construction but the static inference can't see through the
     # bitcast chain (same opt-out as the sharded ragged solve).
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=(r, c), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(r, c), check_vma=False)
 
 
 def ef_consensus(z, omega, resid, *, mode: str, block: int = 256,

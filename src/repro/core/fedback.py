@@ -107,6 +107,7 @@ from .compact import capacity_bounds, init_queue, make_compact_block, \
 from .compress import check_mode, ef_consensus, ef_participant_mean, \
     init_residual
 from .controller import ControllerConfig, init_controller
+from .operands import RoundOperands
 from .engine import (
     consensus_mean,
     dual_ascent,
@@ -453,6 +454,9 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
             preserve the round signature.
 
     Returns round_fn(state[, ctrl_overrides]) -> (state, RoundMetrics).
+    With ``jit`` it also has ``round_fn.lower(state, ...)``; the jitted
+    program takes ``data`` as an argument, never as a constant baked
+    into the executable (``repro.core.operands``).
     """
     if cfg.state_backend not in ("device", "host"):
         raise ValueError(f"unknown state_backend: {cfg.state_backend!r} "
@@ -513,6 +517,10 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
         pin = partial(constrain_clients, mesh=mesh, axis=client_axis)
     else:
         pin = lambda t, **_: t  # noqa: E731
+    # The jitted round takes the data as arguments, never as constants.
+    data_shardings = (None if mesh is None else
+                      jax.tree.map(lambda x: x.sharding, data))
+    data = RoundOperands(data)
 
     solver = partial(_local_solve, loss_fn, rho=rho, lr=cfg.lr,
                      momentum=cfg.momentum)
@@ -650,7 +658,6 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
         _local_tables = tuple(_local_tables)
 
         def _sharded_ragged_solve(theta_init, center, keys):
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             def body(theta_init, center, keys, offsets, sizes, x, y,
@@ -695,10 +702,10 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
                 return theta_out, losses
 
             c, r = P(client_axis), P()
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(c, c, c, c, c, r, r, c),
-                out_specs=(c, c), check_rep=False)
+                out_specs=(c, c), check_vma=False)
             return mapped(theta_init, center, keys,
                           ragged.offsets_array(), ragged.sizes_array(),
                           data["x"], data["y"], _local_tables)
@@ -935,7 +942,7 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
     donate_argnums = (0,) if donate else ()
 
     if mesh is None:
-        return jax.jit(round_fn, donate_argnums=donate_argnums)
+        return data.jit(round_fn, donate_argnums=donate_argnums)
 
     from jax.sharding import NamedSharding, PartitionSpec
     state_sh = fl_state_shardings(mesh, axis=client_axis)
@@ -945,9 +952,10 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
         in_sh += (None,)
     if arrivals_arg:
         in_sh += (NamedSharding(mesh, PartitionSpec(client_axis)),)
-    return jax.jit(round_fn, in_shardings=in_sh,
-                   out_shardings=(state_sh, metrics_sh),
-                   donate_argnums=donate_argnums)
+    return data.jit(round_fn, in_shardings=in_sh,
+                    out_shardings=(state_sh, metrics_sh),
+                    donate_argnums=donate_argnums,
+                    operand_shardings=data_shardings)
 
 
 def make_eval_fn(loss_and_acc_fn: Callable, *, jit: bool = True,

@@ -98,6 +98,7 @@ from .fedback import (
     _masked_local_solve,
     _resolve_kernel_flag,
 )
+from .operands import RoundOperands
 from .selection import make_selection
 from .state import (
     DeferQueue,
@@ -293,10 +294,11 @@ def make_host_round_fn(cfg, loss_fn, data, *, jit: bool = True, mesh=None,
         donate = jax.default_backend() != "cpu"
 
     # Round-static device residents: training data (gathered by slot
-    # index inside the solve program, same op as the device block) and
-    # the CSR index columns.
-    x_dev = jnp.asarray(data["x"])
-    y_dev = jnp.asarray(data["y"])
+    # index inside the solve program, same op as the device block; an
+    # argument of that program, not a constant) and the CSR index
+    # columns.
+    operands = RoundOperands({"x": jnp.asarray(data["x"]),
+                              "y": jnp.asarray(data["y"])})
     if ragged is not None:
         offsets_dev = ragged.offsets_array()
         sizes_dev = ragged.sizes_array()
@@ -371,7 +373,7 @@ def make_host_round_fn(cfg, loss_fn, data, *, jit: bool = True, mesh=None,
                        if cfg.warm_start else th_rows)
         idx_b = jax.vmap(epoch_fn)(keys_rows)
         if ragged is None:
-            x_slots, y_slots = x_dev[idx], y_dev[idx]
+            x_slots, y_slots = operands["x"][idx], operands["y"][idx]
             th_out, losses = jax.vmap(solver)(
                 theta0_rows, center_rows, x_slots, y_slots, idx_b)
         else:
@@ -384,7 +386,8 @@ def make_host_round_fn(cfg, loss_fn, data, *, jit: bool = True, mesh=None,
                     lambda o: jax.lax.dynamic_slice_in_dim(
                         buf, o, block_len, 0))(off_rows)
 
-            x_rows, y_rows = slice_rows(x_dev), slice_rows(y_dev)
+            x_rows, y_rows = (slice_rows(operands["x"]),
+                              slice_rows(operands["y"]))
             if masked:
                 th_out, losses = jax.vmap(masked_solver)(
                     theta0_rows, center_rows, x_rows, y_rows,
@@ -424,8 +427,8 @@ def make_host_round_fn(cfg, loss_fn, data, *, jit: bool = True, mesh=None,
         return omega2, comm2, dists, participant_mean_loss(losses, valid)
 
     plan_step = jax.jit(_plan)
-    solve_step = (jax.jit(_solve, donate_argnums=(3, 4)) if donate
-                  else jax.jit(_solve))
+    solve_step = operands.jit(_solve,
+                              donate_argnums=(3, 4) if donate else ())
     agg_step = (jax.jit(_aggregate, donate_argnums=(0,)) if donate
                 else jax.jit(_aggregate))
     trig_step = jax.jit(partial(trigger_distances,

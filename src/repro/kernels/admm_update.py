@@ -65,12 +65,18 @@ def _kernel2(th_ref, la_ref, w_ref, lam_out, c_out):
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d",
                                              "interpret", "with_z"))
 def admm_update(theta, lam, omega, *, block_n: int = 8, block_d: int = 1024,
-                interpret: bool = True, with_z: bool = True):
+                interpret: bool | None = None, with_z: bool = True):
     """theta/lam: (N, D); omega: (D,) → (λ⁺, z, center) each (N, D).
 
     With ``with_z=False`` the z stream is skipped and the result is
     (λ⁺, center) — the pre-solve half of the round's client update.
+    ``interpret=None`` runs the compiled kernel on a TPU backend and
+    interpret mode elsewhere (``ops._default_interpret``).
     """
+    from .ops import _default_interpret
+
+    if interpret is None:
+        interpret = _default_interpret()
     n, d = theta.shape
     n_pad = -n % block_n
     d_pad = -d % block_d
@@ -99,21 +105,21 @@ def admm_update(theta, lam, omega, *, block_n: int = 8, block_d: int = 1024,
 
 def admm_update_sharded(theta, lam, omega, mesh, *, axis: str = "clients",
                         block_n: int = 8, block_d: int = 1024,
-                        interpret: bool = True, with_z: bool = True):
+                        interpret: bool | None = None,
+                        with_z: bool = True):
     """Client-sharded fused update: ``shard_map`` over the ``clients``
     mesh axis, one kernel launch per device on its local rows.
 
     theta/lam: (N, D) sharded over ``axis``; omega: (D,) replicated.
     Pure elementwise per client row — no collective is introduced.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     kernel = functools.partial(admm_update, block_n=block_n, block_d=block_d,
                                interpret=interpret, with_z=with_z)
     n_out = 3 if with_z else 2
-    fn = shard_map(kernel, mesh=mesh,
-                   in_specs=(P(axis, None), P(axis, None), P(None)),
-                   out_specs=(P(axis, None),) * n_out,
-                   check_rep=False)
+    fn = jax.shard_map(kernel, mesh=mesh,
+                       in_specs=(P(axis, None), P(axis, None), P(None)),
+                       out_specs=(P(axis, None),) * n_out,
+                       check_vma=False)
     return fn(theta, lam, omega)

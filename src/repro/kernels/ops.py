@@ -1,9 +1,11 @@
 """Public kernel entry points.
 
 Each op dispatches between the Pallas TPU kernel and the pure-jnp
-reference.  On this CPU container the kernels execute in interpret mode
-(the kernel *body* runs, validating the exact TPU program); on a real
-TPU backend set ``interpret=False`` (the default flips automatically).
+reference.  ``interpret=None`` (the default everywhere) is resolved by
+:func:`_default_interpret`: compiled kernels on a TPU backend, interpret
+mode elsewhere (the kernel *body* runs on the CPU, validating the exact
+TPU program).  Pass ``interpret=False`` to force compilation, e.g. when
+compiling ahead of time for a described TPU from a CPU host.
 
 ``trigger_sq_norms_pytree`` is the integration point used by the
 FedBack server: it flattens stacked client pytrees into the (N, D)
@@ -34,11 +36,11 @@ from .trigger_norms import (
 
 
 def _default_interpret() -> bool:
+    """The mode ``interpret=None`` means: compiled on TPU only."""
     return jax.default_backend() != "tpu"
 
 
 def trigger_sq_norms(z_prev, omega, *, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
     return _trigger_sq_norms(z_prev, omega, interpret=interpret)
 
 
@@ -50,7 +52,6 @@ def admm_update(theta, lam, omega, *, interpret: bool | None = None,
     form).  With ``mesh`` the kernel runs under ``shard_map`` over the
     client mesh axis — one launch per device on its local rows.
     """
-    interpret = _default_interpret() if interpret is None else interpret
     if mesh is not None:
         return _admm_update_sharded(theta, lam, omega, mesh, axis=axis,
                                     interpret=interpret, with_z=with_z)
@@ -67,7 +68,6 @@ def fused_gss(idx, valid, solved, omega, theta, lam, z_prev=None, *,
     scatters); outputs alias the (N, D) state inputs so the scatter is
     in place.  ``fused_gss_ref`` is the bit-identical jnp form.
     """
-    interpret = _default_interpret() if interpret is None else interpret
     return _fused_gss(idx, valid, solved, omega, theta, lam, z_prev,
                       interpret=interpret, with_z=with_z)
 
@@ -110,7 +110,6 @@ def trigger_sq_norms_pytree(z_prev_stacked, omega, *,
             [x.reshape(n, -1).astype(jnp.float32) for x in z_leaves], axis=1)
         w1d = jnp.concatenate(
             [x.reshape(-1).astype(jnp.float32) for x in w_leaves])
-    interpret = _default_interpret() if interpret is None else interpret
     if mesh is not None:
         return _trigger_sq_norms_sharded(z2d, w1d, mesh, axis=axis,
                                          interpret=interpret)

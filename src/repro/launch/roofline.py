@@ -1,7 +1,10 @@
 """Roofline-term extraction from a compiled dry-run artifact.
 
-Hardware model: TPU v5e —
-    197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the per-chip peaks in :data:`PEAKS`, keyed by
+``jax.Device.device_kind``; the analytic models below price a TPU v5e
+(:data:`MODEL_KIND`) — 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI
+link.  A measurement on a chip looks its peaks up with :func:`peaks`,
+which refuses a device kind the table does not hold.
 
 ``compiled.cost_analysis()`` returns **per-device** (post-SPMD) FLOPs
 and bytes (validated empirically: a (8,64)×(64,128) matmul on a (2,4)
@@ -22,10 +25,43 @@ from typing import Any
 
 from repro.utils.hlo import collective_inventory, total_collective_bytes
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-LINK_BW = 50e9  # bytes/s per ICI link (conservative single-link model)
-PCIE_BW = 32e9  # bytes/s host<->device (PCIe gen4 x16, sustained)
+#: Per-chip peaks by ``device_kind``, each entry with its source.
+PEAKS = {
+    # TPU v5e.  Source: Google Cloud documentation, "TPU v5e" — 197
+    # TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+    # of ICI per chip over 4 links (50 GB/s each).  ``pcie_bw`` is not
+    # in that source: it is assumed (PCIe Gen4 x16 nominal) and only
+    # feeds the host-backend stream model.
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bw": 819e9,
+        "ici_link_bw": 50e9,
+        "pcie_bw": 32e9,
+    },
+}
+
+#: The chip the analytic models price.
+MODEL_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind``; unknown kinds
+    raise rather than borrow another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            "an entry with its source to repro.launch.roofline.PEAKS"
+        ) from None
+
+
+PEAK_FLOPS = peaks(MODEL_KIND)["bf16_flops"]
+HBM_BW = peaks(MODEL_KIND)["hbm_bw"]
+LINK_BW = peaks(MODEL_KIND)["ici_link_bw"]  # single-link model
+PCIE_BW = peaks(MODEL_KIND)["pcie_bw"]
 
 
 def model_flops_per_device(cfg, *, mode: str, batch: int, seq: int,

@@ -301,11 +301,12 @@ def jaxpr_eqn_counts(jaxpr) -> dict:
 def jaxpr_pallas_kernel_names(jaxpr) -> dict:
     """Kernel-function-name → count over every ``pallas_call`` equation.
 
-    Recurses like :func:`jaxpr_eqn_counts`; the name comes from the
-    equation's ``name_and_src_info`` param (the kernel body's python
-    function name, e.g. ``_kernel3`` / ``_fused_gss3``), so rules can
-    budget *which* kernels a round launches, not just how many.
-    Unnamed pallas calls count under ``"<unknown>"``.
+    Recurses like :func:`jaxpr_eqn_counts`; the name is the one the TPU
+    lowering gives the kernel: the equation's ``name`` param when the
+    caller set one, else the kernel body's python function name (e.g.
+    ``_kernel3`` / ``_fused_gss3``) from the kernel jaxpr's debug info,
+    so rules can budget *which* kernels a round launches, not just how
+    many.  Unnamed pallas calls count under ``"<unknown>"``.
     """
     from collections import Counter
 
@@ -323,8 +324,10 @@ def jaxpr_pallas_kernel_names(jaxpr) -> dict:
     def visit(jx):
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
-                info = eqn.params.get("name_and_src_info")
-                counts[getattr(info, "name", None) or "<unknown>"] += 1
+                debug = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+                name = (eqn.params.get("name")
+                        or getattr(debug, "func_name", None))
+                counts[name or "<unknown>"] += 1
             for v in eqn.params.values():
                 visit_param(v)
 
@@ -377,7 +380,8 @@ def toplevel_elementwise_shapes(jaxpr, prims=("add", "sub", "mul")) -> list:
         for eqn in jx.eqns:
             if eqn.primitive.name in prims:
                 shapes.extend(tuple(ov.aval.shape) for ov in eqn.outvars)
-            elif eqn.primitive.name in ("pjit", "closed_call") and depth < 1:
+            elif eqn.primitive.name in ("jit", "pjit", "closed_call") \
+                    and depth < 1:
                 for v in eqn.params.values():
                     if hasattr(v, "jaxpr"):
                         visit(v.jaxpr, depth + 1)

@@ -75,10 +75,14 @@ class TestSeededMutations:
     def test_host_transfer_staged_in_round(self):
         # jax.device_put of a host scalar inside the traced body — the
         # classic "constant built per round instead of at build time".
+        # The explicit target device is what stages the transfer: a
+        # placement-free device_put of a host value folds into a
+        # constant at trace time.
         def host_staging(body):
             def wrapped(state, *args, **kw):
                 state = state._replace(
-                    round=state.round + jax.device_put(np.int32(0)))
+                    round=state.round + jax.device_put(np.int32(0),
+                                                       jax.devices()[0]))
                 return body(state, *args, **kw)
             return wrapped
 
@@ -94,7 +98,8 @@ class TestSeededMutations:
         def full_width_leak(solve):
             def wrapped(omega, idx, keys_rows, th_tiles, lam_tiles):
                 stray = jax.device_put(
-                    np.zeros((DEFAULT_N, DEFAULT_DIM), np.float32))
+                    np.zeros((DEFAULT_N, DEFAULT_DIM), np.float32),
+                    jax.devices()[0])
                 return solve(omega + 0.0 * stray[0], idx, keys_rows,
                              th_tiles, lam_tiles)
             return wrapped
@@ -136,7 +141,7 @@ class TestSeededMutations:
         assert failing_rules(art) == []
 
     def test_f64_leak(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             j64 = jax.make_jaxpr(lambda x: x * 2.0)(
                 jnp.ones((4,), jnp.float64))
         fake = types.SimpleNamespace(

@@ -1,7 +1,7 @@
 """Fused gather→ADMM→scatter commit: parity with the three-pass path.
 
 * kernel level: interpret-mode ``fused_gss`` is bit-identical to the
-  jnp ``fused_gss_ref`` oracle — both ``with_z`` forms, lane-padded D,
+  jnp ``fused_gss_ref`` oracle — both ``with_z`` forms, partial tiles,
   masked (invalid) lanes, and untouched rows preserved through the
   aliased outputs; the recomputed λ⁺ matches the ``admm_update`` Pallas
   kernel bit for bit (same ``_kernel2``/``_kernel3`` op order);
@@ -48,9 +48,11 @@ class TestFusedKernel:
     @pytest.mark.parametrize("n,c,d", [
         (16, 8, 128),    # lane-aligned D
         (64, 24, 256),
-        (16, 5, 100),    # D padded up to 128
-        (8, 3, 7),       # tiny padded D
+        (16, 5, 100),    # D below one 128-lane block
+        (8, 3, 7),       # tiny D
         (32, 32, 64),    # every row planned
+        (100, 16, 2100),  # partial last row tile and D block
+        (13, 13, 300),   # every row of a partial tile planned
     ])
     @pytest.mark.parametrize("with_z", [True, False])
     def test_bit_identical_to_ref(self, n, c, d, with_z):

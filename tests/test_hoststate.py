@@ -146,26 +146,37 @@ class TestHostParity:
         _assert_bitexact(states[0], states[1])
 
     def test_metrics_match_device(self):
-        """Scalar round metrics agree (the trace consumers read these)."""
+        """Scalar round metrics agree (the trace consumers read these).
+
+        Everything is exact except ``train_loss``, which may differ by
+        one ulp: the per-slot losses are bit-identical, but the device
+        reduces their masked sum inside the fused round program and the
+        host backend inside its aggregate program, and XLA may order
+        that 12-term fp32 sum differently in the two (the same sum
+        reduced sequentially in both programs agrees bit for bit).
+        """
         data, params0, ls, spec, _ = _problem("none")
         cfg = _cfg()
 
         def trace(c):
             st = init_state(c, params0, spec=spec)
             fn = make_round_fn(c, ls, data, spec=spec)
-            rows = []
+            rows, losses = [], []
             for _ in range(4):
                 st, m = fn(st)
                 rows.append((int(m.num_events), int(m.num_deferred),
                              int(m.realized_capacity),
                              float(m.realized_slack),
-                             float(m.train_loss),
                              np.asarray(m.distances).tolist(),
                              np.asarray(m.committed).tolist()))
-            return rows
+                losses.append(np.float32(m.train_loss))
+            return rows, np.asarray(losses, np.float32)
 
-        assert trace(cfg) == trace(
+        dev_rows, dev_loss = trace(cfg)
+        host_rows, host_loss = trace(
             dataclasses.replace(cfg, state_backend="host"))
+        assert dev_rows == host_rows
+        np.testing.assert_array_max_ulp(dev_loss, host_loss, maxulp=1)
 
     def test_run_rounds_compatible(self):
         """The generic trace driver works unchanged on the host backend."""
