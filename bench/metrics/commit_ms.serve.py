@@ -1,0 +1,11 @@
+"""Device time of ``fedback/commit`` per tick of the serve() window, in ms:
+the commit of the solved rows (``fused_gss``, or the scatters).  The
+union of the scope's op intervals inside the window over the steps.
+Moves ``commits_per_s``."""
+from spans import device_ms
+
+
+def read(ctx):
+    if ctx.kind != "serve":
+        return None
+    return device_ms(ctx, "commit")

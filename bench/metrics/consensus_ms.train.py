@@ -1,0 +1,11 @@
+"""Device time of ``fedback/consensus`` per round of the training window,
+in ms: the consensus mean and the round's metrics.  The union of the
+scope's op intervals inside the window over the steps.  Moves
+``rounds_per_s``."""
+from spans import device_ms
+
+
+def read(ctx):
+    if ctx.kind != "rounds":
+        return None
+    return device_ms(ctx, "consensus")

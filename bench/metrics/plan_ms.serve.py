@@ -1,0 +1,11 @@
+"""Device time of ``fedback/plan`` per tick of the serve() window, in ms:
+selection, the controller, the compact plan, the queue update and the
+row gathers.  The union of the scope's op intervals inside the window
+over the steps.  Moves ``commits_per_s``."""
+from spans import device_ms
+
+
+def read(ctx):
+    if ctx.kind != "serve":
+        return None
+    return device_ms(ctx, "plan")

@@ -461,8 +461,6 @@ def run(print_fn=print, *, n_clients: int = 1024, n_points: int = 16,
     # model (round_fn.planned_bytes ≡ roofline.host_stream_bytes ≡ the
     # host-transfer-budget tracecheck rule).
     h_slack = 1.5
-    phase_keys = ("plan_s", "h2d_s", "solve_s", "d2h_s", "scatter_s",
-                  "agg_s")
     for sec, h_n, h_pts, h_rate, h_rounds, h_repeats in (
             ("host_stream_n65536", 65536, 4, 0.02, 3, 2),
             ("host_stream_n1m", 1_000_000, 2, 0.001, 2, 1)):
@@ -483,14 +481,10 @@ def run(print_fn=print, *, n_clients: int = 1024, n_points: int = 16,
         hstate, hm0 = hrf(hstate)
         jax.block_until_ready((hstate.omega, hm0))
         h_compile_s = time.perf_counter() - t0
-        snap = dict(hrf.stats)
         t0 = time.perf_counter()
         hstate, hhist = run_rounds(hrf, hstate, h_rounds)
         jax.block_until_ready((hstate.omega, hhist))
-        wall_first_us = (time.perf_counter() - t0) / h_rounds * 1e6
-        h_us = wall_first_us
-        phase_us = {k: (hrf.stats[k] - snap[k]) / h_rounds * 1e6
-                    for k in phase_keys}
+        h_us = (time.perf_counter() - t0) / h_rounds * 1e6
         for _ in range(h_repeats - 1):
             t0 = time.perf_counter()
             hstate, extra = run_rounds(hrf, hstate, h_rounds)
@@ -512,15 +506,6 @@ def run(print_fn=print, *, n_clients: int = 1024, n_points: int = 16,
             == done * planned["server_pass_d2h"]
             and planned["row_stream_h2d"] == model["row_stream_h2d_bytes"]
             and planned["row_stream_d2h"] == model["row_stream_d2h_bytes"])
-        # Phase timers tile the measured wall, so any *positive* gap of
-        # Σphases over the wall is copy time hidden under compute; on
-        # CPU transfers are memcpys on the compute thread, so the
-        # honest measured fraction is ~0 (the modeled fraction is the
-        # PCIe/HBM-roofline value a device part can hide).
-        stream_us = phase_us["h2d_s"] + phase_us["d2h_s"]
-        overlap_measured = max(
-            0.0, (sum(phase_us.values()) - wall_first_us)
-            / max(stream_us, 1e-9))
         report[sec] = {
             "n_clients": h_n, "dim": hspec.dim, "participation": h_rate,
             "capacity_slack": h_slack, "rounds": h_rounds + 1,
@@ -541,11 +526,6 @@ def run(print_fn=print, *, n_clients: int = 1024, n_points: int = 16,
             "host_state_bytes": int(hstate.host_state_bytes()),
             "device_state_sub_full_matrix": bool(
                 hstate.device_state_bytes() < h_n * hspec.dim * 4),
-            "plan_us": phase_us["plan_s"], "h2d_us": phase_us["h2d_s"],
-            "solve_us": phase_us["solve_s"], "d2h_us": phase_us["d2h_s"],
-            "scatter_us": phase_us["scatter_s"],
-            "agg_us": phase_us["agg_s"],
-            "overlap_fraction_measured": overlap_measured,
             "modeled_overlap_fraction": model["modeled_overlap_fraction"],
             "modeled_stream_s": model["stream_s"],
             "modeled_solve_s": model["solve_s"],
@@ -557,8 +537,7 @@ def run(print_fn=print, *, n_clients: int = 1024, n_points: int = 16,
             f"d2h/round={int(row_d2h_pr)}B "
             f"bytes_match_plan={int(bytes_match)} "
             f"device_state={int(hstate.device_state_bytes())}B "
-            f"overlap={overlap_measured:.2f}"
-            f"/{model['modeled_overlap_fraction']:.2f}(model)")
+            f"overlap={model['modeled_overlap_fraction']:.2f}(model)")
         del hdata, hstate, hrf  # free the (N, ...) buffers before 1M
 
     # Bit-parity vs the device backend at small N: same config modulo

@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.utils.pytree import tree_broadcast_like
+from repro.utils.spans import scope
 
 from .controller import demand_load_step
 from .state import DeferQueue
@@ -312,66 +313,74 @@ def make_compact_block(solver: Callable, epoch_fn: Callable, capacity: int,
 
     def block(events, distances, eligible, age, qload, theta, lam, z_prev,
               omega, x, y, keys, offsets=None, sizes=None):
-        limit = (adaptive_limit(qload, c_min, capacity)
-                 if adaptive else None)
-        plan = compact_plan(events, distances, capacity, age=age,
-                            limit=limit, eligible=eligible)
-        queue = queue_update(DeferQueue(age=age, load=qload), plan,
-                             alpha=alpha)
-        th_rows = gather_rows(theta, plan.idx)
-        lam_rows = gather_rows(lam, plan.idx)
+        # Device scopes fedback/plan, solve and commit (op metadata
+        # only), one per stage, as the round program names them.
+        with scope("plan"):
+            limit = (adaptive_limit(qload, c_min, capacity)
+                     if adaptive else None)
+            plan = compact_plan(events, distances, capacity, age=age,
+                                limit=limit, eligible=eligible)
+            queue = queue_update(DeferQueue(age=age, load=qload), plan,
+                                 alpha=alpha)
+            th_rows = gather_rows(theta, plan.idx)
+            lam_rows = gather_rows(lam, plan.idx)
 
-        if is_admm:
-            if use_admm_kernel and not fused:
-                from repro.kernels import ops
-                lam_new_rows, center_rows = ops.admm_update(
-                    th_rows, lam_rows, omega, with_z=False)
+            if is_admm:
+                if use_admm_kernel and not fused:
+                    from repro.kernels import ops
+                    lam_new_rows, center_rows = ops.admm_update(
+                        th_rows, lam_rows, omega, with_z=False)
+                else:
+                    # The fused path re-derives λ⁺ inside the commit
+                    # kernel — the pre-solve pass stays jnp (the solver
+                    # only needs the center), so one round launches ONE
+                    # state kernel.
+                    from repro.core.engine import dual_ascent, prox_center
+                    lam_new_rows = dual_ascent(lam_rows, th_rows, omega)
+                    center_rows = prox_center(omega, lam_new_rows)
             else:
-                # The fused path re-derives λ⁺ inside the commit kernel
-                # — the pre-solve pass stays jnp (the solver only needs
-                # the center), so one round launches ONE state kernel.
-                from repro.core.engine import dual_ascent, prox_center
-                lam_new_rows = dual_ascent(lam_rows, th_rows, omega)
-                center_rows = prox_center(omega, lam_new_rows)
-        else:
-            lam_new_rows = lam_rows  # stays zero
-            center_rows = tree_broadcast_like(omega, capacity)
+                lam_new_rows = lam_rows  # stays zero
+                center_rows = tree_broadcast_like(omega, capacity)
 
-        theta0_rows = (tree_broadcast_like(omega, capacity) if warm_start
-                       else th_rows)
-        # Data and PRNG keys flow through the same capacity slots: the
-        # vmapped solver streams C rows of x/y (C CSR slices of the
-        # pooled buffer when ragged), not N.
-        if ragged is None:
-            x_slots, y_slots = gather_rows(x, plan.idx), \
-                gather_rows(y, plan.idx)
-            off_rows = size_rows = None
-        else:
-            x_slots, y_slots = x, y  # pooled; sliced inside the solver
-            off_rows = gather_rows(offsets, plan.idx)
-            size_rows = gather_rows(sizes, plan.idx)
-        th_out_rows, losses = solve_slots(
-            theta0_rows, center_rows, x_slots, y_slots,
-            gather_rows(keys, plan.idx), off_rows, size_rows)
-        if fused:
-            # One pass over the state instead of three: the fused op
-            # re-derives λ⁺ from the gathered θ/λ rows (bit-identical
-            # _kernel3 op order — λ is unchanged since the pre-solve
-            # pass), assembles z = θ_out + λ⁺ in VMEM, and scatters all
-            # three outputs in place on their aliased input buffers.
-            from repro.kernels import ops
-            op = ops.fused_gss if use_fused_kernel else ops.fused_gss_ref
-            theta_new, lam_new, z_new = op(
-                plan.idx, plan.valid, th_out_rows, omega, theta, lam,
-                z_prev, with_z=True)
-        else:
-            z_rows = (jax.tree.map(jnp.add, th_out_rows, lam_new_rows)
-                      if is_admm else th_out_rows)
-            theta_new = scatter_rows(theta, th_out_rows, plan.idx,
-                                     plan.valid)
-            z_new = scatter_rows(z_prev, z_rows, plan.idx, plan.valid)
-            lam_new = (scatter_rows(lam, lam_new_rows, plan.idx,
-                                    plan.valid) if is_admm else lam)
+            theta0_rows = (tree_broadcast_like(omega, capacity)
+                           if warm_start else th_rows)
+            # Data and PRNG keys flow through the same capacity slots:
+            # the vmapped solver streams C rows of x/y (C CSR slices of
+            # the pooled buffer when ragged), not N.
+            if ragged is None:
+                x_slots, y_slots = gather_rows(x, plan.idx), \
+                    gather_rows(y, plan.idx)
+                off_rows = size_rows = None
+            else:
+                x_slots, y_slots = x, y  # pooled; sliced inside the solver
+                off_rows = gather_rows(offsets, plan.idx)
+                size_rows = gather_rows(sizes, plan.idx)
+            key_rows = gather_rows(keys, plan.idx)
+        with scope("solve"):
+            th_out_rows, losses = solve_slots(
+                theta0_rows, center_rows, x_slots, y_slots, key_rows,
+                off_rows, size_rows)
+        with scope("commit"):
+            if fused:
+                # One pass over the state instead of three: the fused op
+                # re-derives λ⁺ from the gathered θ/λ rows
+                # (bit-identical _kernel3 op order — λ is unchanged
+                # since the pre-solve pass), assembles z = θ_out + λ⁺ in
+                # VMEM, and scatters all three outputs in place on their
+                # aliased input buffers.
+                from repro.kernels import ops
+                op = ops.fused_gss if use_fused_kernel else ops.fused_gss_ref
+                theta_new, lam_new, z_new = op(
+                    plan.idx, plan.valid, th_out_rows, omega, theta, lam,
+                    z_prev, with_z=True)
+            else:
+                z_rows = (jax.tree.map(jnp.add, th_out_rows, lam_new_rows)
+                          if is_admm else th_out_rows)
+                theta_new = scatter_rows(theta, th_out_rows, plan.idx,
+                                         plan.valid)
+                z_new = scatter_rows(z_prev, z_rows, plan.idx, plan.valid)
+                lam_new = (scatter_rows(lam, lam_new_rows, plan.idx,
+                                        plan.valid) if is_admm else lam)
         return (theta_new, lam_new, z_new, queue.age, queue.load,
                 plan.committed, losses, plan.valid,
                 plan.limit.reshape((1,)))

@@ -98,6 +98,7 @@ import numpy as np
 from repro.optim.sgd import sgd_init, sgd_step
 from repro.utils.flatstate import FlatSpec
 from repro.utils.ragged import RaggedSpec
+from repro.utils.spans import gc_spans, scope, span
 from repro.utils.pytree import (
     tree_broadcast_like,
     tree_zeros_like,
@@ -597,23 +598,19 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
             center = tree_broadcast_like(state.omega, n)
         return lam_new, center
 
-    def dense_client_update(state, events, data_rng):
+    def dense_client_update(state, center, data_rng):
         """All-N solve behind the event mask (the bitwise baseline).
 
-        Returns *service proposals* (θ_out, λ⁺, z) — the caller gates
-        them into state (synchronous ``gated_commit``) or routes them
-        through the delay pipeline (``staleness_commit``)."""
-        lam_new, center = _duals_and_centers(state)
+        Returns the solved θ_out rows and their losses: with λ⁺ they
+        are the *service proposals* the caller gates into state
+        (synchronous ``gated_commit``) or routes through the delay
+        pipeline (``staleness_commit``)."""
         theta_init = (tree_broadcast_like(state.omega, n) if cfg.warm_start
                       else state.theta)
         idx = jax.vmap(epoch_fn)(jax.random.split(data_rng, n))
         theta_out, losses = jax.vmap(solver)(
             pin(theta_init), pin(center), data["x"], data["y"], pin(idx))
-        theta_out = pin(theta_out)
-
-        z_new = (jax.tree.map(jnp.add, theta_out, lam_new) if is_admm
-                 else theta_out)
-        return theta_out, lam_new, z_new, losses
+        return pin(theta_out), losses
 
     # Per-bucket gather constants, staged once at build time.  The
     # traced round closes over them (they become jaxpr constants), so
@@ -710,16 +707,15 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
                           ragged.offsets_array(), ragged.sizes_array(),
                           data["x"], data["y"], _local_tables)
 
-    def ragged_dense_update(state, events, data_rng):
+    def ragged_dense_update(state, center, data_rng):
         """All-N solve over pooled CSR data, one vmap per size bucket.
 
-        Same service-proposal contract as ``dense_client_update``; the
-        solver streams each client's minibatches straight out of the
-        pooled buffer (global indices ``offset_i + local_idx``), so a
-        uniform spec — one bucket, no padding — reproduces the
-        rectangular dense path bit for bit.
+        Same contract as ``dense_client_update``; the solver streams
+        each client's minibatches straight out of the pooled buffer
+        (global indices ``offset_i + local_idx``), so a uniform spec —
+        one bucket, no padding — reproduces the rectangular dense path
+        bit for bit.
         """
-        lam_new, center = _duals_and_centers(state)
         theta_init = pin(tree_broadcast_like(state.omega, n)
                          if cfg.warm_start else state.theta)
         center = pin(center)
@@ -731,10 +727,7 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
             # only collective in the round stays the consensus mean.
             theta_out, losses = _sharded_ragged_solve(theta_init,
                                                       center, keys)
-            theta_out = pin(theta_out)
-            z_new = (jax.tree.map(jnp.add, theta_out, lam_new)
-                     if is_admm else theta_out)
-            return theta_out, lam_new, z_new, losses
+            return pin(theta_out), losses
         theta_out = theta_init  # every row overwritten below
         losses = jnp.zeros((n,), jnp.float32)
         for bucket, mem, offs, szs in _bucket_consts:
@@ -758,10 +751,7 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
                 lambda acc, r, m=mem: acc.at[m].set(r.astype(acc.dtype)),
                 theta_out, th)
             losses = losses.at[mem].set(ls)
-        theta_out = pin(theta_out)
-        z_new = (jax.tree.map(jnp.add, theta_out, lam_new) if is_admm
-                 else theta_out)
-        return theta_out, lam_new, z_new, losses
+        return pin(theta_out), losses
 
     # Dynamic-gather companions of the static CSR spec (the compact
     # plan indexes them by slot; client-stacked, so they shard with the
@@ -771,8 +761,10 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
 
     def compact_client_update(state, events, distances, eligible,
                               data_rng):
-        """Gather demand rows into capacity slots, solve C rows, scatter."""
-        keys = jax.random.split(data_rng, n)
+        """Gather demand rows into capacity slots, solve C rows, scatter
+        (the block scopes its own plan, solve and commit)."""
+        with scope("plan"):
+            keys = jax.random.split(data_rng, n)
         args = (events, distances, eligible, state.queue.age,
                 state.queue.load, state.theta, state.lam,
                 state.z_prev, state.omega, data["x"], data["y"], keys)
@@ -781,34 +773,44 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
         return block(*args)
 
     def round_body(state: FLState, ctrl_overrides, arrivals=None):
-        rng, sel_rng, data_rng = jax.random.split(state.rng, 3)
+        # Each stage runs under its own device scope (fedback/trigger,
+        # plan, solve, commit, consensus; the compact block scopes its
+        # own plan, solve and commit), so every op of the round names
+        # its layer in the trace.  Scopes change op metadata only.
 
         # --- server: trigger distances + selection --------------------
-        distances = _trigger(cfg, state, mesh, client_axis)
-        if async_mode:
-            # A client with an in-flight solve is ineligible to re-fire
-            # until its payload lands (one outstanding solve per client).
-            inflight = state.inflight
-            eligible = inflight.ttl == 0
-            admit = eligible if arrivals is None else eligible & arrivals
-            events = select.decide(sel_rng, state, distances,
-                                   ctrl_overrides,
-                                   eligible=admit) & admit
-            ctrl = None  # stepped below on commit-time measurements
-        elif arrivals is not None:
-            # Serve step: fresh events only from this tick's arrivals.
-            # Plan eligibility stays all-ones — deferred demand is
-            # served whether or not the client re-arrives (a queued
-            # client's work must never be dropped by a quiet tick).
-            eligible = jnp.ones((n,), bool)
-            events = select.decide(sel_rng, state, distances,
-                                   ctrl_overrides,
-                                   eligible=arrivals) & arrivals
-            ctrl = select.measure(state.ctrl, events, ctrl_overrides)
-        else:
-            eligible = jnp.ones((n,), bool)
-            events, ctrl = select(sel_rng, state, distances,
-                                  ctrl_overrides=ctrl_overrides)
+        with scope("trigger"):
+            distances = _trigger(cfg, state, mesh, client_axis)
+        with scope("plan"):
+            rng, sel_rng, data_rng = jax.random.split(state.rng, 3)
+            if async_mode:
+                # A client with an in-flight solve is ineligible to
+                # re-fire until its payload lands (one outstanding
+                # solve per client).
+                inflight = state.inflight
+                eligible = inflight.ttl == 0
+                admit = eligible if arrivals is None else eligible & arrivals
+                events = select.decide(sel_rng, state, distances,
+                                       ctrl_overrides,
+                                       eligible=admit) & admit
+                ctrl = None  # stepped below on commit-time measurements
+            elif arrivals is not None:
+                # Serve step: fresh events only from this tick's
+                # arrivals.  Plan eligibility stays all-ones — deferred
+                # demand is served whether or not the client re-arrives
+                # (a queued client's work must never be dropped by a
+                # quiet tick).
+                eligible = jnp.ones((n,), bool)
+                events = select.decide(sel_rng, state, distances,
+                                       ctrl_overrides,
+                                       eligible=arrivals) & arrivals
+                ctrl = select.measure(state.ctrl, events, ctrl_overrides)
+            else:
+                eligible = jnp.ones((n,), bool)
+                events, ctrl = select(sel_rng, state, distances,
+                                      ctrl_overrides=ctrl_overrides)
+            if not cfg.compact:
+                lam_p, center = _duals_and_centers(state)
 
         # --- client-side computation (service proposals) --------------
         if cfg.compact:
@@ -816,105 +818,117 @@ def make_round_fn(cfg: FLConfig, loss_fn: Callable, data: dict[str, Any],
              loss_mask, limits) = \
                 compact_client_update(state, events, distances, eligible,
                                       data_rng)
-            queue = state.queue._replace(age=q_age, load=q_load)
-            # Σ over shards of the per-device commit limits (shape
-            # (n_shards,) under the mesh, (1,) on a single device).
-            realized_capacity = jnp.sum(limits)
-            num_deferred = jnp.sum((q_age > 0).astype(jnp.int32))
+            with scope("plan"):
+                queue = state.queue._replace(age=q_age, load=q_load)
+                # Σ over shards of the per-device commit limits (shape
+                # (n_shards,) under the mesh, (1,) on a single device).
+                realized_capacity = jnp.sum(limits)
+                num_deferred = jnp.sum((q_age > 0).astype(jnp.int32))
         else:
             client_update = (ragged_dense_update if ragged is not None
                              else dense_client_update)
-            theta_p, lam_p, z_p, losses = \
-                client_update(state, events, data_rng)
+            with scope("solve"):
+                theta_p, losses = client_update(state, center, data_rng)
+            with scope("commit"):
+                z_p = (jax.tree.map(jnp.add, theta_p, lam_p) if is_admm
+                       else theta_p)
             serviced, loss_mask = events, events
             queue = state.queue
             realized_capacity = jnp.asarray(n, jnp.int32)
             num_deferred = None  # 0 below (dense rounds never defer)
 
         # --- commit: synchronous gate or bounded-staleness pipeline ----
-        if async_mode:
-            land, direct, defer, new_ttl = staleness_masks(
-                serviced, inflight.delay, inflight.ttl)
-            theta, park_theta = staleness_commit(
-                state.theta, theta_p, inflight.theta, land, direct, defer)
-            lam, park_lam = staleness_commit(
-                state.lam, lam_p, inflight.lam, land, direct, defer)
-            z_prev, park_z = staleness_commit(
-                state.z_prev, z_p, inflight.z, land, direct, defer)
-            z_prev = pin(z_prev)
-            committed = direct | land
-            # Commit-time participation accounting: the controller
-            # measures an issue δ_i rounds after the fact, with the
-            # feasible-rate ceiling as anti-windup.
-            hist = record_issue(inflight.hist, events, state.round)
-            measured = measured_commits(hist, inflight.delay, state.round)
-            ctrl = select.measure(state.ctrl, measured, ctrl_overrides,
-                                  staleness_delay=inflight.delay)
-            new_inflight = InFlight(delay=inflight.delay, ttl=new_ttl,
-                                    theta=park_theta, lam=park_lam,
-                                    z=park_z, hist=hist)
-            num_inflight = jnp.sum((new_ttl > 0).astype(jnp.int32))
-            num_landed = jnp.sum(land.astype(jnp.int32))
-            if num_deferred is None:
-                num_deferred = jnp.zeros((), jnp.int32)
-        elif cfg.compact:
-            theta, lam, z_prev = theta_p, lam_p, pin(z_p)
-            committed, new_inflight = serviced, state.inflight
-            num_inflight = num_landed = jnp.zeros((), jnp.int32)
-        else:
-            theta = gated_commit(events, theta_p, state.theta)
-            lam = gated_commit(events, lam_p, state.lam)
-            z_prev = pin(gated_commit(events, z_p, state.z_prev))
-            committed, new_inflight = events, state.inflight
-            num_inflight = num_landed = jnp.zeros((), jnp.int32)
+        with scope("commit"):
+            if async_mode:
+                land, direct, defer, new_ttl = staleness_masks(
+                    serviced, inflight.delay, inflight.ttl)
+                theta, park_theta = staleness_commit(
+                    state.theta, theta_p, inflight.theta, land, direct,
+                    defer)
+                lam, park_lam = staleness_commit(
+                    state.lam, lam_p, inflight.lam, land, direct, defer)
+                z_prev, park_z = staleness_commit(
+                    state.z_prev, z_p, inflight.z, land, direct, defer)
+                z_prev = pin(z_prev)
+                committed = direct | land
+                # Commit-time participation accounting: the controller
+                # measures an issue δ_i rounds after the fact, with the
+                # feasible-rate ceiling as anti-windup.
+                hist = record_issue(inflight.hist, events, state.round)
+                measured = measured_commits(hist, inflight.delay,
+                                            state.round)
+                ctrl = select.measure(state.ctrl, measured, ctrl_overrides,
+                                      staleness_delay=inflight.delay)
+                new_inflight = InFlight(delay=inflight.delay, ttl=new_ttl,
+                                        theta=park_theta, lam=park_lam,
+                                        z=park_z, hist=hist)
+                num_inflight = jnp.sum((new_ttl > 0).astype(jnp.int32))
+                num_landed = jnp.sum(land.astype(jnp.int32))
+                if num_deferred is None:
+                    num_deferred = jnp.zeros((), jnp.int32)
+            elif cfg.compact:
+                theta, lam, z_prev = theta_p, lam_p, pin(z_p)
+                committed, new_inflight = serviced, state.inflight
+                num_inflight = num_landed = jnp.zeros((), jnp.int32)
+            else:
+                theta = gated_commit(events, theta_p, state.theta)
+                lam = gated_commit(events, lam_p, state.lam)
+                z_prev = pin(gated_commit(events, z_p, state.z_prev))
+                committed, new_inflight = events, state.inflight
+                num_inflight = num_landed = jnp.zeros((), jnp.int32)
 
         # --- server-side aggregation -----------------------------------
-        num_events = jnp.sum(events.astype(jnp.int32))
-        num_committed = jnp.sum(committed.astype(jnp.int32))
-        if num_deferred is None:
-            num_deferred = num_events - num_committed
-        comm = state.comm
-        if is_admm:
-            # ω^{k+1} = (1/N) Σ_i z_i^prev — stale entries included
-            # (Eq. 2.4); under staleness the freshest *available* rows.
-            if compress != "none":
-                omega, comm = ef_consensus(
-                    z_prev, state.omega, comm, mode=compress,
-                    block=cfg.compress_block, mesh=mesh, axis=client_axis)
+        with scope("consensus"):
+            num_events = jnp.sum(events.astype(jnp.int32))
+            num_committed = jnp.sum(committed.astype(jnp.int32))
+            if num_deferred is None:
+                num_deferred = num_events - num_committed
+            comm = state.comm
+            if is_admm:
+                # ω^{k+1} = (1/N) Σ_i z_i^prev — stale entries included
+                # (Eq. 2.4); under staleness the freshest *available*
+                # rows.
+                if compress != "none":
+                    omega, comm = ef_consensus(
+                        z_prev, state.omega, comm, mode=compress,
+                        block=cfg.compress_block, mesh=mesh,
+                        axis=client_axis)
+                else:
+                    omega = consensus_mean(z_prev)
             else:
-                omega = consensus_mean(z_prev)
-        else:
-            # FedAvg/FedProx: non-weighted mean over participants only.
-            # (z_prev carries this round's committed uploads; stale rows
-            # are masked out by ``committed``.)
-            if compress != "none":
-                omega, comm = ef_participant_mean(
-                    z_prev, committed, state.omega, comm, num_committed,
-                    mode=compress, block=cfg.compress_block, mesh=mesh,
-                    axis=client_axis)
-            else:
-                omega = participant_mean(z_prev, committed, state.omega,
-                                         num_events=num_committed)
+                # FedAvg/FedProx: non-weighted mean over participants
+                # only.  (z_prev carries this round's committed uploads;
+                # stale rows are masked out by ``committed``.)
+                if compress != "none":
+                    omega, comm = ef_participant_mean(
+                        z_prev, committed, state.omega, comm,
+                        num_committed, mode=compress,
+                        block=cfg.compress_block, mesh=mesh,
+                        axis=client_axis)
+                else:
+                    omega = participant_mean(z_prev, committed, state.omega,
+                                             num_events=num_committed)
 
-        rate_floor = cfg.participation * n
-        metrics = RoundMetrics(
-            events=events,
-            num_events=num_events,
-            distances=distances,
-            delta=ctrl.delta,
-            load=ctrl.load,
-            train_loss=participant_mean_loss(losses, loss_mask),
-            num_deferred=num_deferred,
-            realized_capacity=realized_capacity,
-            realized_slack=(realized_capacity.astype(jnp.float32)
-                            / (rate_floor if rate_floor > 0 else 1.0)),
-            num_inflight=num_inflight,
-            num_landed=num_landed,
-            committed=committed,
-        )
-        new_state = FLState(theta=theta, lam=lam, z_prev=z_prev, omega=omega,
-                            ctrl=ctrl, rng=rng, round=state.round + 1,
-                            queue=queue, inflight=new_inflight, comm=comm)
+            rate_floor = cfg.participation * n
+            metrics = RoundMetrics(
+                events=events,
+                num_events=num_events,
+                distances=distances,
+                delta=ctrl.delta,
+                load=ctrl.load,
+                train_loss=participant_mean_loss(losses, loss_mask),
+                num_deferred=num_deferred,
+                realized_capacity=realized_capacity,
+                realized_slack=(realized_capacity.astype(jnp.float32)
+                                / (rate_floor if rate_floor > 0 else 1.0)),
+                num_inflight=num_inflight,
+                num_landed=num_landed,
+                committed=committed,
+            )
+            new_state = FLState(theta=theta, lam=lam, z_prev=z_prev,
+                                omega=omega, ctrl=ctrl, rng=rng,
+                                round=state.round + 1, queue=queue,
+                                inflight=new_inflight, comm=comm)
         return new_state, metrics
 
     if ctrl_arg and arrivals_arg:
@@ -982,11 +996,18 @@ def run_rounds(round_fn, state: FLState, num_rounds: int):
     donation/async dispatch pipeline across rounds.  The returned
     metrics pytree has leaves of shape (num_rounds, ...); fetch to host
     once at the end (``jax.device_get``/``np.asarray``) if needed.
+
+    Each dispatch is a ``fedback/round`` host span (arg ``i``), the
+    final stack ``fedback/run_rounds.stack``, and each collector pass
+    inside the call ``fedback/gc`` (``repro.utils.spans``).
     """
     history = []
-    for _ in range(num_rounds):
-        state, m = round_fn(state)
-        history.append(m)
-    metrics = (jax.tree.map(lambda *xs: jnp.stack(xs), *history)
-               if history else None)
+    with gc_spans():
+        for i in range(num_rounds):
+            with span("round", i=i):
+                state, m = round_fn(state)
+            history.append(m)
+        with span("run_rounds.stack"):
+            metrics = (jax.tree.map(lambda *xs: jnp.stack(xs), *history)
+                       if history else None)
     return state, metrics

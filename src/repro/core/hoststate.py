@@ -61,7 +61,6 @@ O(C·D) working set↔0 (transient) + O(N) vectors + the (D,) ω —
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Any, NamedTuple
 
@@ -70,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.utils.flatstate import FlatSpec
+from repro.utils.spans import span
 
 from .compact import (
     adaptive_limit,
@@ -434,24 +434,20 @@ def make_host_round_fn(cfg, loss_fn, data, *, jit: bool = True, mesh=None,
     trig_step = jax.jit(partial(trigger_distances,
                                 metric=cfg.trigger_metric))
 
+    # Byte counters of the host glue; its phases are host spans
+    # fedback/host.plan, host.h2d, host.solve, host.d2h, host.scatter
+    # and host.agg (``repro.utils.spans``), on the profiler's clock.
     stats = {"rounds": 0, "h2d_row_bytes": 0, "d2h_row_bytes": 0,
              "h2d_full_bytes": 0, "d2h_full_bytes": 0,
-             "d2h_plan_bytes": 0,
-             # Wall-clock per glue phase (seconds, cumulative) — the
-             # bench's phase breakdown.  Timers bracket dispatch sites,
-             # so async backends attribute hidden copy time to the
-             # phase that forces the sync, not the one that issued it.
-             "plan_s": 0.0, "h2d_s": 0.0, "solve_s": 0.0, "d2h_s": 0.0,
-             "scatter_s": 0.0, "agg_s": 0.0}
+             "d2h_plan_bytes": 0}
     _delay_np: list = []  # static per-client delays, fetched once
 
     def _put_tiles(rows: np.ndarray):
         # Dispatch every tile's H2D back-to-back (double-buffered
         # stream: the runtime overlaps copy t+1 with compute on t).
-        t0 = time.perf_counter()
-        tiles = tuple(jax.device_put(rows[a:b]) for a, b in spans)
+        with span("host.h2d"):
+            tiles = tuple(jax.device_put(rows[a:b]) for a, b in spans)
         stats["h2d_row_bytes"] += rows.nbytes
-        stats["h2d_s"] += time.perf_counter() - t0
         return tiles
 
     def round_fn(state: HostState):
@@ -465,78 +461,75 @@ def make_host_round_fn(cfg, loss_fn, data, *, jit: bool = True, mesh=None,
                                  "distances": trig_step(state.omega,
                                                         z_dev)})
         inflight = state.inflight
-        t0 = time.perf_counter()
-        p = plan_step(state.rng, state.round, state.ctrl,
-                      state.queue.age, state.queue.load, state.distances,
-                      None if inflight is None else inflight.delay,
-                      None if inflight is None else inflight.ttl,
-                      None if inflight is None else inflight.hist)
-        np_idx = np.asarray(p["idx"])
-        np_valid = np.asarray(p["valid"])
+        with span("host.plan"):
+            p = plan_step(state.rng, state.round, state.ctrl,
+                          state.queue.age, state.queue.load,
+                          state.distances,
+                          None if inflight is None else inflight.delay,
+                          None if inflight is None else inflight.ttl,
+                          None if inflight is None else inflight.hist)
+            np_idx = np.asarray(p["idx"])
+            np_valid = np.asarray(p["valid"])
         stats["d2h_plan_bytes"] += np_idx.nbytes + np_valid.nbytes
-        stats["plan_s"] += time.perf_counter() - t0
 
         th_tiles = _put_tiles(state.theta[np_idx])
         lam_tiles = _put_tiles(state.lam[np_idx])
-        t0 = time.perf_counter()
-        th_out, lam_new, z_rows, losses = solve_step(
-            state.omega, p["idx"], p["keys_rows"], th_tiles, lam_tiles)
-        stats["solve_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        np_th = np.asarray(th_out)
-        np_lam = np.asarray(lam_new)
-        np_z = np.asarray(z_rows)
+        with span("host.solve"):
+            th_out, lam_new, z_rows, losses = solve_step(
+                state.omega, p["idx"], p["keys_rows"], th_tiles, lam_tiles)
+        with span("host.d2h"):
+            np_th = np.asarray(th_out)
+            np_lam = np.asarray(lam_new)
+            np_z = np.asarray(z_rows)
         stats["d2h_row_bytes"] += np_th.nbytes + np_lam.nbytes + np_z.nbytes
-        stats["d2h_s"] += time.perf_counter() - t0
 
         # --- host scatter: the valid slots' distinct client rows ------
-        t0 = time.perf_counter()
-        slot = np.flatnonzero(np_valid)
-        cids = np_idx[slot]
-        new_inflight = inflight
-        if async_mode:
-            if not _delay_np:
-                _delay_np.append(np.asarray(inflight.delay))
-            np_land = np.asarray(p["land"])
-            stats["d2h_plan_bytes"] += np_land.nbytes
-            land_rows = np.flatnonzero(np_land)
-            for buf, park in ((state.theta, inflight.theta),
-                              (state.lam, inflight.lam),
-                              (state.z_prev, inflight.z)):
-                buf[land_rows] = park[land_rows]
-            d0 = _delay_np[0][cids] == 0
-            for buf, park, rows in ((state.theta, inflight.theta, np_th),
-                                    (state.lam, inflight.lam, np_lam),
-                                    (state.z_prev, inflight.z, np_z)):
-                buf[cids[d0]] = rows[slot[d0]]  # direct commits
-                park[cids[~d0]] = rows[slot[~d0]]  # deferred → park
-            new_inflight = InFlight(delay=inflight.delay, ttl=p["ttl"],
-                                    theta=inflight.theta,
-                                    lam=inflight.lam, z=inflight.z,
-                                    hist=p["hist"])
-        else:
-            state.theta[cids] = np_th[slot]
-            state.z_prev[cids] = np_z[slot]
-            if is_admm:
-                state.lam[cids] = np_lam[slot]
-        stats["scatter_s"] += time.perf_counter() - t0
+        with span("host.scatter"):
+            slot = np.flatnonzero(np_valid)
+            cids = np_idx[slot]
+            new_inflight = inflight
+            if async_mode:
+                if not _delay_np:
+                    _delay_np.append(np.asarray(inflight.delay))
+                np_land = np.asarray(p["land"])
+                stats["d2h_plan_bytes"] += np_land.nbytes
+                land_rows = np.flatnonzero(np_land)
+                for buf, park in ((state.theta, inflight.theta),
+                                  (state.lam, inflight.lam),
+                                  (state.z_prev, inflight.z)):
+                    buf[land_rows] = park[land_rows]
+                d0 = _delay_np[0][cids] == 0
+                for buf, park, rows in (
+                        (state.theta, inflight.theta, np_th),
+                        (state.lam, inflight.lam, np_lam),
+                        (state.z_prev, inflight.z, np_z)):
+                    buf[cids[d0]] = rows[slot[d0]]  # direct commits
+                    park[cids[~d0]] = rows[slot[~d0]]  # deferred → park
+                new_inflight = InFlight(delay=inflight.delay, ttl=p["ttl"],
+                                        theta=inflight.theta,
+                                        lam=inflight.lam, z=inflight.z,
+                                        hist=p["hist"])
+            else:
+                state.theta[cids] = np_th[slot]
+                state.z_prev[cids] = np_z[slot]
+                if is_admm:
+                    state.lam[cids] = np_lam[slot]
 
         # --- one full-width server pass -------------------------------
-        t0 = time.perf_counter()
-        z_dev = jax.device_put(state.z_prev)
-        stats["h2d_full_bytes"] += state.z_prev.nbytes
-        comm_dev = None
-        if compress != "none":
-            comm_dev = jax.device_put(state.comm)
-            stats["h2d_full_bytes"] += state.comm.nbytes
-        omega2, comm2, dists, train_loss = agg_step(
-            z_dev, state.omega, comm_dev, p["committed"],
-            p["num_committed"], losses, p["valid"])
-        comm_np = state.comm
-        if compress != "none":
-            comm_np = np.asarray(comm2)
-            stats["d2h_full_bytes"] += comm_np.nbytes
-        stats["agg_s"] += time.perf_counter() - t0
+        with span("host.agg"):
+            z_dev = jax.device_put(state.z_prev)
+            stats["h2d_full_bytes"] += state.z_prev.nbytes
+            comm_dev = None
+            if compress != "none":
+                comm_dev = jax.device_put(state.comm)
+                stats["h2d_full_bytes"] += state.comm.nbytes
+            omega2, comm2, dists, train_loss = agg_step(
+                z_dev, state.omega, comm_dev, p["committed"],
+                p["num_committed"], losses, p["valid"])
+            comm_np = state.comm
+            if compress != "none":
+                comm_np = np.asarray(comm2)
+                stats["d2h_full_bytes"] += comm_np.nbytes
 
         metrics = RoundMetrics(
             events=p["events"], num_events=p["num_events"],

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils.spans import gc_spans, span
+
 TRACE_KINDS = ("sync", "poisson", "diurnal", "bursty")
 
 
@@ -187,6 +189,12 @@ def serve(round_fn, state, trace, *, warmup: bool = False,
     before timing starts (safe under donation — only the copy's
     buffers are consumed), so wall-clock latencies exclude compile.
 
+    Each tick is a ``fedback/serve.tick`` host span (arg ``t``) holding
+    ``serve.upload``, ``serve.step``, ``serve.fetch`` (ends where the
+    commit is stamped) and ``serve.ledger`` (args ``committed`` and
+    ``deferred``: the tick's commits and queue length); each collector
+    pass is ``fedback/gc`` (``repro.utils.spans``).
+
     Returns ``(state, ServeReport)`` — or ``(state, report, history)``
     with ``collect_metrics=True``, where ``history`` is the list of
     per-tick ``RoundMetrics`` (host copies).
@@ -208,43 +216,53 @@ def serve(round_fn, state, trace, *, warmup: bool = False,
     history: list = []
     final_deferred = final_inflight = 0
 
-    t_begin = time.perf_counter()
-    for t in range(ticks):
-        t_dispatch = time.perf_counter()
-        arrivals = jnp.asarray(trace[t])
-        state, metrics = round_fn(state, arrivals)
-        events = np.asarray(metrics.events)
-        committed = np.asarray(metrics.committed)
-        t_done = time.perf_counter()
-        if collect_metrics:
-            history.append(jax.device_get(metrics))
-        final_deferred = int(metrics.num_deferred)
-        final_inflight = int(metrics.num_inflight)
+    with gc_spans():
+        t_begin = time.perf_counter()
+        for t in range(ticks):
+            with span("serve.tick", t=t):
+                t_dispatch = time.perf_counter()
+                with span("serve.upload"):
+                    arrivals = jnp.asarray(trace[t])
+                with span("serve.step"):
+                    state, metrics = round_fn(state, arrivals)
+                with span("serve.fetch"):
+                    events = np.asarray(metrics.events)
+                    committed = np.asarray(metrics.committed)
+                    t_done = time.perf_counter()
+                with span("serve.ledger") as ledger:
+                    if collect_metrics:
+                        history.append(jax.device_get(metrics))
+                    final_deferred = int(metrics.num_deferred)
+                    final_inflight = int(metrics.num_inflight)
+                    ledger.set_metadata(committed=int(committed.sum()),
+                                        deferred=final_deferred)
 
-        # Demand is one bit per client: a commit closes the *earliest*
-        # open admission, and a re-fire while pending (or on the very
-        # tick the commit lands) merges into it — exactly the
-        # DeferQueue's events|age semantics, so no extra admission.
-        was_pending = pending_tick >= 0
-        landed = committed & was_pending
-        for i in np.nonzero(landed)[0]:
-            latency_ticks.append(t - pending_tick[i])
-            latency_us.append((t_done - pending_wall[i]) * 1e6)
-            pending_tick[i] = -1
-        commits_total += int(landed.sum())
+                    # Demand is one bit per client: a commit closes the
+                    # *earliest* open admission, and a re-fire while
+                    # pending (or on the very tick the commit lands)
+                    # merges into it — exactly the DeferQueue's
+                    # events|age semantics, so no extra admission.
+                    was_pending = pending_tick >= 0
+                    landed = committed & was_pending
+                    for i in np.nonzero(landed)[0]:
+                        latency_ticks.append(t - pending_tick[i])
+                        latency_us.append((t_done - pending_wall[i]) * 1e6)
+                        pending_tick[i] = -1
+                    commits_total += int(landed.sum())
 
-        fresh = events & ~was_pending
-        admitted_total += int(fresh.sum())
-        # Same-tick service: admitted and committed in one step.
-        instant = fresh & committed
-        for _ in range(int(instant.sum())):
-            latency_ticks.append(0)
-            latency_us.append((t_done - t_dispatch) * 1e6)
-        commits_total += int(instant.sum())
-        opened = fresh & ~instant
-        pending_tick[opened] = t
-        pending_wall[opened] = t_dispatch
-    wall_s = time.perf_counter() - t_begin
+                    fresh = events & ~was_pending
+                    admitted_total += int(fresh.sum())
+                    # Same-tick service: admitted and committed in one
+                    # step.
+                    instant = fresh & committed
+                    for _ in range(int(instant.sum())):
+                        latency_ticks.append(0)
+                        latency_us.append((t_done - t_dispatch) * 1e6)
+                    commits_total += int(instant.sum())
+                    opened = fresh & ~instant
+                    pending_tick[opened] = t
+                    pending_wall[opened] = t_dispatch
+        wall_s = time.perf_counter() - t_begin
 
     pending_final = int((pending_tick >= 0).sum())
     report = ServeReport(

@@ -103,12 +103,15 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, size):
     ("unfused", ("trigger_sq_norms", "admm_update")),
 ])
 def test_round_compiles_with_its_kernels(one_chip, monkeypatch, chip_smoke,
-                                         variant, expected):
+                                         request, variant, expected):
     from repro.core import init_state, make_round_fn
     from repro.kernels import ops
 
     # On this CPU backend the kernels would resolve to interpret mode.
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    # The kernels' jitted wrappers keep the trace made here; drop it, so
+    # that a later CPU test at the same shapes traces interpret mode.
+    request.addfinalizer(jax.clear_caches)
     cs = chip_smoke
     problem = cs.build_problem(seed=0, n_clients=16, n_train=1600,
                                n_test=100, hidden=8)
