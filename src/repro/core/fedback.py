@@ -101,6 +101,7 @@ from repro.utils.ragged import RaggedSpec
 from repro.utils.spans import gc_spans, scope, span
 from repro.utils.pytree import (
     tree_broadcast_like,
+    tree_stack,
     tree_zeros_like,
 )
 from .compact import capacity_bounds, init_queue, make_compact_block, \
@@ -988,18 +989,28 @@ def make_eval_fn(loss_and_acc_fn: Callable, *, jit: bool = True,
     return jax.jit(eval_fn) if jit else eval_fn
 
 
+def stack_metrics(history: list):
+    """Per-round metrics as host arrays of shape (rounds, ...): one
+    stack program on the device (``tree_stack``), then one fetch.
+
+    Every caller reads the metrics on the host, and a training run that
+    keeps them would otherwise keep them in device memory."""
+    return jax.device_get(tree_stack(history)) if history else None
+
+
 def run_rounds(round_fn, state: FLState, num_rounds: int):
     """Python-loop driver returning stacked per-round metrics.
 
-    Metrics stay on device until the final stack — the loop never calls
-    ``device_get``, so each ``round_fn`` dispatch is asynchronous and
-    donation/async dispatch pipeline across rounds.  The returned
-    metrics pytree has leaves of shape (num_rounds, ...); fetch to host
-    once at the end (``jax.device_get``/``np.asarray``) if needed.
+    The loop never fetches, so each ``round_fn`` dispatch is
+    asynchronous and donation/async dispatch pipeline across rounds.
+    The call ends with ``stack_metrics``: the returned metrics are host
+    arrays with leaves of shape (num_rounds, ...), and the call returns
+    once its rounds have run.
 
     Each dispatch is a ``fedback/round`` host span (arg ``i``), the
-    final stack ``fedback/run_rounds.stack``, and each collector pass
-    inside the call ``fedback/gc`` (``repro.utils.spans``).
+    final stack and fetch ``fedback/run_rounds.stack``, and each
+    collector pass inside the call ``fedback/gc``
+    (``repro.utils.spans``).
     """
     history = []
     with gc_spans():
@@ -1008,6 +1019,5 @@ def run_rounds(round_fn, state: FLState, num_rounds: int):
                 state, m = round_fn(state)
             history.append(m)
         with span("run_rounds.stack"):
-            metrics = (jax.tree.map(lambda *xs: jnp.stack(xs), *history)
-                       if history else None)
+            metrics = stack_metrics(history)
     return state, metrics

@@ -40,6 +40,8 @@ import numpy as np
 
 from repro.utils.spans import gc_spans, span
 
+from .fedback import stack_metrics
+
 TRACE_KINDS = ("sync", "poisson", "diurnal", "bursty")
 
 
@@ -288,15 +290,13 @@ def serve(round_fn, state, trace, *, warmup: bool = False,
 
 def run_trace(round_fn, state, trace):
     """Device-side trace driver (no latency accounting): step every
-    tick, stack the metrics — the serve analogue of ``run_rounds``
-    (golden traces and parity tests use it)."""
+    tick, stack the metrics to the host (``stack_metrics``) — the serve
+    analogue of ``run_rounds`` (golden traces and parity tests use it)."""
     history = []
     for t in range(np.asarray(trace).shape[0]):
         state, m = round_fn(state, jnp.asarray(np.asarray(trace)[t]))
         history.append(m)
-    metrics = (jax.tree.map(lambda *xs: jnp.stack(xs), *history)
-               if history else None)
-    return state, metrics
+    return state, stack_metrics(history)
 
 
 __all__ = [

@@ -54,8 +54,14 @@ def tree_norm(tree):
     return jnp.sqrt(tree_sq_norm(tree))
 
 
+@jax.jit
 def tree_stack(trees):
-    """Stack a list of pytrees along a new leading axis."""
+    """Stack a list of pytrees along a new leading axis.
+
+    One device program: a call is one dispatch, not one eager op per
+    array, and jit keys its cache by the list's structure, shapes and
+    length, so each compiles once.
+    """
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *trees)
 
 

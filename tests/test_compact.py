@@ -330,14 +330,29 @@ class TestAdaptiveCapacity:
 
 
 class TestRunRoundsDriver:
-    def test_metrics_stay_on_device_and_stack(self):
+    def test_metrics_stay_on_device_and_stack(self, monkeypatch):
         n = 4
         data, params0, ls = make_least_squares(n, 8, 5)
         cfg = _cfg(n)
         state = init_state(cfg, params0)
         round_fn = make_round_fn(cfg, ls, data)
-        state2, hist = run_rounds(round_fn, state, 5)
-        assert isinstance(hist.events, jax.Array)  # no host fetch inside
+        calls, device_get = [], jax.device_get
+
+        def counted_round(st):
+            calls.append("round")
+            return round_fn(st)
+
+        def counted_get(tree):
+            calls.append("fetch")
+            return device_get(tree)
+
+        monkeypatch.setattr(jax, "device_get", counted_get)
+        state2, hist = run_rounds(counted_round, state, 5)
+        monkeypatch.undo()
+        # No host fetch inside the loop: one fetch, of the stacked
+        # metrics, after the last round.
+        assert calls == ["round"] * 5 + ["fetch"]
+        assert isinstance(hist.events, np.ndarray)
         assert hist.events.shape == (5, n)
         assert hist.num_events.shape == (5,)
         # matches a manual python loop driving the same program
