@@ -236,6 +236,13 @@ def build_problem(cell: Cell, seed: int) -> Problem:
                    loss_fn=cell.model.program_loss(cell.cfg))
 
 
+def host_peak_gb() -> float:
+    """This process's peak resident host memory so far, in GB."""
+    import resource
+
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024 / 1e9
+
+
 def host_state(state) -> dict:
     import jax
 
@@ -443,7 +450,8 @@ def _rounds_cell(cell, round_fn, state, seconds, tracer, phases, counter,
     phases["warmup_chunk_s"] = time.perf_counter() - t
     compiled = counter.snapshot()
     setup_s = time.perf_counter() - t_start
-    say(phase="setup", setup_s=setup_s, **phases, **compiled)
+    say(phase="setup", setup_s=setup_s, **phases, **compiled,
+        host_peak_gb=host_peak_gb())
 
     window = cell.traffic["trace_seconds"] if tracer.on else seconds
     window = min(window, seconds)
@@ -454,7 +462,8 @@ def _rounds_cell(cell, round_fn, state, seconds, tracer, phases, counter,
     committed = np.concatenate([np.asarray(jax.device_get(h.committed))
                                 for h in history])
     say(phase="window", rounds=rounds, wall_s=wall,
-        compiles_in_window=counter.compiles - before)
+        compiles_in_window=counter.compiles - before,
+        host_peak_gb=host_peak_gb())
     peak = _peak_bytes()
     del state, history, round_fn
     gc.collect()
@@ -490,7 +499,8 @@ def _serve_cell(cell, round_fn, state, seed, seconds, tracer, phases,
     tick_s = rep.wall_s / calib
     pending0 = np.asarray(jax.device_get(state.queue.age)) > 0
     setup_s = time.perf_counter() - t_start
-    say(phase="setup", setup_s=setup_s, tick_s=tick_s, **phases, **compiled)
+    say(phase="setup", setup_s=setup_s, tick_s=tick_s, **phases, **compiled,
+        host_peak_gb=host_peak_gb())
 
     window = min(tr["trace_seconds"], seconds) if tracer.on else seconds
     start = check + calib
@@ -510,7 +520,8 @@ def _serve_cell(cell, round_fn, state, seed, seconds, tracer, phases,
     say(phase="window", ticks=ticks, wall_s=rep.wall_s,
         commits=int(committed.sum()), arrivals=int(window_trace.sum()),
         events=int(events.sum()), deferred_max=int(deferred.max()),
-        compiles_in_window=counter.compiles - before)
+        compiles_in_window=counter.compiles - before,
+        host_peak_gb=host_peak_gb())
     peak = _peak_bytes()
     from reference import capacity
 
@@ -578,7 +589,7 @@ def compare(cell, problem, seed, prog_metrics, prog_state, arrivals, *,
     ``row_gap_median``, the median of :func:`check.row_gaps`, and both
     sides' largest round loss and row norm (no limit: calibration
     only)."""
-    from check import round_gaps, row_gaps, state_gap
+    from check import round_gaps, row_gaps, row_norms, state_gap
 
     rounds = len(prog_metrics)
     if dtype is not None or precision is not None or fault is not None:
@@ -601,7 +612,7 @@ def compare(cell, problem, seed, prog_metrics, prog_state, arrivals, *,
             nums[side + "_loss_max"] = float(np.max(
                 [m["train_loss"] for m in ms]))
             nums[side + "_theta_norm_max"] = float(np.max(
-                np.linalg.norm(st["theta"].astype(np.float64), axis=1)))
+                row_norms(st["theta"])))
     return nums
 
 
@@ -611,17 +622,20 @@ def _start_state(cell, problem) -> dict:
     flat = np.asarray(ravel_pytree(problem.params0)[0], np.float64)
     n = cell.cfg["n_clients"]
     rows = np.broadcast_to(flat, (n, flat.shape[0]))
-    return {"theta": rows, "lam": np.zeros_like(rows), "z": rows,
-            "omega": flat}
+    return {"theta": rows, "lam": np.broadcast_to(0.0, rows.shape),
+            "z": rows, "omega": flat}
 
 
 def _finish(cell, problem, seed, trace, devices, tracer, out) -> dict:
     from check import judge, ledger_faults
 
+    t = time.perf_counter()
     nums = compare(cell, problem, seed, out["prog_metrics"],
                    out["prog_state"], out["arrivals"])
     if out["ledger"] is not None:
         nums["ledger_faults"] = ledger_faults(**out["ledger"])
+    say(phase="check", check_s=time.perf_counter() - t,
+        host_peak_gb=host_peak_gb())
     correct, table = judge(nums, cell.limits)
     dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,

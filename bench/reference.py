@@ -102,7 +102,8 @@ def init_state(cfg: dict, params0, seed: int, dtype=jnp.float32) -> dict:
 def make_round(model, cfg: dict, data: dict, layout, params0, *,
                dtype=jnp.float32, precision: str = "highest",
                fault: str | None = None):
-    """Jitted ``round(state, arrivals) -> (state, metrics)``; pass
+    """Jitted ``round(state, arrivals) -> (state, metrics)``, which
+    donates the state it is given; pass
     ``arrivals=None`` for a synchronous round.  ``precision`` is the
     matmul precision of a float32 round (the control of a
     configuration run at "highest" is the same round at "high")."""
@@ -235,7 +236,10 @@ def make_round(model, cfg: dict, data: dict, layout, params0, *,
         return new, metrics
 
     # The data are arguments, never constants baked into the program.
-    jitted = jax.jit(round_fn)
+    # The state is donated: a round writes its new state over the old
+    # one, so a federation whose (N, D) state fills most of the chip
+    # holds it once, not twice.
+    jitted = jax.jit(round_fn, donate_argnums=0)
 
     def run(st, arrivals=None, hint=None):
         ctx = (jax.default_matmul_precision(precision)

@@ -1,6 +1,6 @@
 """The benchmark's own pieces: every cell resolves by name, the trace
-reduction, the byte and FLOP counts, the ledger, the entry's refusal to
-run without a TPU."""
+reduction, the byte and FLOP counts, the ledger, the host check's blocks,
+the entry's refusal to run without a TPU."""
 import json
 import os
 import subprocess
@@ -95,6 +95,54 @@ def test_round_mfu_counts_committed_work():
     flops = (2 * 14 * 42 + 2 * 2 * 42) * 3 * 10
     assert mfu.solve_flops(ctx) == flops
     assert mfu.read(ctx) == pytest.approx(100 * flops / 2.0 / 1e4)
+
+
+def _whole_matrix_row_gaps(prog, ref, start):
+    """``check.row_gaps`` with every matrix cast to float64 at once."""
+    gaps = []
+    for key in ("theta", "lam", "z"):
+        p = prog[key].astype(np.float64)
+        r = ref[key].astype(np.float64)
+        moved = np.linalg.norm(r - start[key], axis=1)
+        scale = float(np.median(moved[moved > 0])) if np.any(moved > 0) \
+            else 1e-30
+        gap = np.linalg.norm(p - r, axis=1) / np.maximum(moved, scale)
+        gaps.append(gap[(moved > 0) | (gap != 0)])
+    return np.concatenate(gaps)
+
+
+@pytest.mark.parametrize("rows", [1, 7, 64, 1_000])
+def test_blocked_state_check_equals_whole_matrix(rows, monkeypatch):
+    import check
+    from check import row_gaps, row_norms, state_gap
+
+    monkeypatch.setattr(check, "BLOCK_ROWS", rows)
+
+    rng = np.random.default_rng(11)
+    n, d = 64, 37
+    x0 = rng.normal(size=d)
+    start = {"theta": np.broadcast_to(x0, (n, d)),
+             "lam": np.zeros((n, d)), "z": np.broadcast_to(x0, (n, d)),
+             "omega": x0}
+    moved = rng.random(n) < 0.4  # the rest stay where they started
+    ref, prog = {}, {}
+    for key in ("theta", "lam", "z"):
+        r = (start[key] + moved[:, None] * rng.normal(size=(n, d)))
+        ref[key] = r.astype(np.float32)
+        noise = 1e-3 * rng.normal(size=(n, d)) * (rng.random(n) < 0.5)[
+            :, None]
+        prog[key] = (ref[key] + noise).astype(np.float32)
+    ref["omega"] = ref["z"].mean(axis=0)
+    prog["omega"] = prog["z"].mean(axis=0)
+    whole = _whole_matrix_row_gaps(prog, ref, start)
+    np.testing.assert_array_equal(row_gaps(prog, ref, start), whole)
+    gap = state_gap(prog, ref, start)
+    om = np.linalg.norm(prog["omega"].astype(np.float64) - ref["omega"]) \
+        / np.linalg.norm(ref["omega"].astype(np.float64) - x0)
+    assert gap == max(float(np.max(whole)), om)
+    np.testing.assert_array_equal(
+        row_norms(prog["theta"]),
+        np.linalg.norm(prog["theta"].astype(np.float64), axis=1))
 
 
 HAND_TRACE = '''

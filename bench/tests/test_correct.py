@@ -6,6 +6,8 @@ fails, once for each fault a cell can have: a step that returns its
 state unchanged, half of each batch left out, an answer altered where
 it is produced.  (The exchange between chips does not exist in these
 one-chip cells.)"""
+import os
+
 import jax.numpy as jnp
 import pytest
 
@@ -52,6 +54,44 @@ def test_control_is_not_correct(cell):
                            dtype=jnp.bfloat16)
     ok, table = judge(nums, c.limits)
     assert not ok, table
+
+
+@pytest.mark.parametrize("name", ["paper_mnist_mlp", "paper_cifar_cnn"])
+def test_donated_reference_equals_undonated(name, monkeypatch):
+    """Flat shards (MNIST) and the pooled layout (CIFAR, a configuration
+    with no cell, run under the MNIST sync cell's traffic)."""
+    import jax
+    import numpy as np
+
+    import reference
+
+    c = harness.resolve("paper_mnist_mlp.sync_rounds")
+    c.cfg = dict(harness.load_json(os.path.join(
+        harness.BENCH, "configs", name + ".json")), **SMALL[name])
+    c.model = harness.load_module(os.path.join(
+        harness.BENCH, "configs", name + ".py"), "bench_model_" + name)
+    harness.setup_jax(False, 1)
+    harness.import_program()
+    problem = harness.build_problem(c, SEED)
+
+    def rounds():
+        st = reference.init_state(c.cfg, problem.params0, SEED)
+        step = reference.make_round(c.model, c.cfg, problem.data,
+                                    problem.layout, problem.params0)
+        given, metrics = st, []
+        for _ in range(c.traffic["check_rounds"]):
+            st, m = step(st)
+            metrics.append(m)
+        return given, jax.device_get((metrics, st))
+
+    given, donated = rounds()
+    assert all(given[k].is_deleted() for k in ("theta", "lam", "z"))
+    jit = jax.jit
+    monkeypatch.setattr(jax, "jit", lambda fn, donate_argnums=(), **kw:
+                        jit(fn, **kw))
+    given, kept = rounds()
+    assert not any(v.is_deleted() for v in jax.tree.leaves(given))
+    jax.tree.map(np.testing.assert_array_equal, donated, kept)
 
 
 def _unchanged(round_fn):
